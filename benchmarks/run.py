@@ -12,6 +12,9 @@ import sys
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         compression_bench,
         fig3_fig4_oneshot,

@@ -322,10 +322,10 @@ def run(out_path: str = "BENCH_serve.json") -> dict:
         f"{stripe['kv_pool_bytes']}B at benchmark occupancy")
 
     # sharded decode: page-axis pool sharding over every visible device
-    from repro import compat
+    from repro.launch.mesh import make_mesh
 
     n_dev = len(jax.devices())
-    mesh = compat.make_mesh((n_dev,), ("data",))
+    mesh = make_mesh((n_dev,), ("data",))
     reqs = _workload(cfg, np.random.default_rng(0), n_requests, slots, prompt_len)
     sharded = _serve(cfg, packed, reqs, "continuous", slots, max_seq,
                      page=PAGE, n_pages=N_PAGES, mesh=mesh)
@@ -847,7 +847,7 @@ def _assert_replay_floors(report: dict, base: dict) -> None:
 def run_replay(out_path: str = "BENCH_serve_replay.json") -> dict:
     import os
 
-    from repro import compat
+    from repro.launch.mesh import make_mesh
     from repro.configs.base import load_arch
     from repro.models import zoo
     from repro.train import pruning
@@ -885,7 +885,7 @@ def run_replay(out_path: str = "BENCH_serve_replay.json") -> dict:
     }
     n_dev = len(jax.devices())
     rows["sharing_sharded"] = case(
-        mesh=compat.make_mesh((n_dev,), ("data",)), prefix_share=True)
+        mesh=make_mesh((n_dev,), ("data",)), prefix_share=True)
     rows["sharing_sharded"]["n_devices"] = n_dev
 
     # the workload shares by construction: the sharing run must hit, copy

@@ -26,6 +26,9 @@ from repro.kernels import ops, ref
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     rng = np.random.default_rng(0)
     n_out, n_in = 256, 512
     row = np.exp(rng.normal(scale=0.6, size=(n_out, 1)))

@@ -64,6 +64,7 @@ def build_workload(cfg, n_requests, prompt_len, rng, shared_prefix=0):
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
     from repro.configs.base import load_arch
     from repro.models import zoo
     from repro.serve import Scheduler
@@ -102,6 +103,7 @@ def main():
                          "re-drive it, asserting event- and token-identical "
                          "behaviour (use the same scheduler flags)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = load_arch("qwen2_0_5b").reduced(n_layers=4, d_model=256, n_heads=4,
                                           n_kv_heads=2, d_ff=512, vocab=2048,

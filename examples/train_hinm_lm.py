@@ -15,13 +15,12 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import jax
-
-from repro import compat
 import jax.numpy as jnp
 import numpy as np
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
     from repro.configs.base import load_arch
     from repro.data.pipeline import SyntheticLMData
     from repro.launch.mesh import make_host_mesh
@@ -37,6 +36,7 @@ def main():
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--ckpt", default="/tmp/hinm_lm_ckpt")
     args = ap.parse_args()
+    enable_compile_cache()
 
     base = load_arch("qwen2_0_5b")
     if args.scale == "tiny":
@@ -81,7 +81,7 @@ def main():
     import logging
 
     logging.basicConfig(level=logging.INFO, format="%(message)s")
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = loop.run(state, jitted, batches(), lcfg,
                          on_step=lambda s, m: losses.append(m.get("loss")),
                          mask_schedule=mask_cb)

@@ -37,21 +37,46 @@ CostMode = Literal["hinm", "vector"]
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "cost_mode"))
-def _tile_retained(tiles: jax.Array, cfg: HiNMConfig, cost_mode: str) -> jax.Array:
+def _retained(tiles: jax.Array, cfg: HiNMConfig, cost_mode: str) -> jax.Array:
     """Retained saliency of each (V, n_in) tile under the target pattern.
 
-    tiles: (B, V, n_in) -> (B,) retained saliency.
+    tiles: (B, V, n_in) -> (B,). The same number as
+    ``sum(tile * hinm_mask(tile))`` without building the mask: gather the
+    kept columns in their stable order, then sum the top-N of every M
+    group (or, for "vector", every kept column).
     """
 
     def one(tile):
+        cols = sparsity.kept_column_ids(tile, cfg)[0]          # (K,)
+        kept = jnp.take(tile, cols, axis=1)                     # (V, K)
         if cost_mode == "vector":
-            mask = sparsity.vector_mask(tile, cfg)
-        else:
-            mask = sparsity.hinm_mask(tile, cfg)
-        return jnp.sum(tile * mask)
+            return jnp.sum(kept)
+        v, k = kept.shape
+        groups = kept.reshape(v, k // cfg.m, cfg.m)
+        return jnp.sum(jax.lax.top_k(groups, cfg.n)[0])
 
     return jax.vmap(one)(tiles)
+
+
+_tile_retained = jax.jit(_retained, static_argnames=("cfg", "cost_mode"))
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "cost_mode"))
+def _assignment_retained(base_rows: jax.Array, clus_rows: jax.Array,
+                         cfg: HiNMConfig, cost_mode: str) -> jax.Array:
+    """(P, P) retained saliency of partition i's base rows joined with
+    cluster j's rows — the OCP assignment cost, in one dispatch.
+
+    base_rows (P, V-s, n_in), clus_rows (P, s, n_in)."""
+    p = clus_rows.shape[0]
+
+    def row(base_i):
+        tiles = jnp.concatenate(
+            [jnp.broadcast_to(base_i[None], (p,) + base_i.shape), clus_rows],
+            axis=1)                                             # (P, V, n_in)
+        return _retained(tiles, cfg, cost_mode)
+
+    return jax.lax.map(row, base_rows)
 
 
 @functools.partial(jax.jit, static_argnames=("n", "m"))
@@ -143,14 +168,10 @@ def ocp(
         # ---- assignment: Hungarian on exact Eq.(4) cost
         base_rows = sal[bases.reshape(-1)].reshape(p, v - s, n_in)
         clus_rows = sal[clusters.reshape(-1)].reshape(p, s, n_in)
-        cost = np.empty((p, p), dtype=np.float64)
         totals = base_rows.sum(axis=(1, 2))[:, None] + clus_rows.sum(axis=(1, 2))[None, :]
-        clus_j = jnp.asarray(clus_rows)
-        for i in range(p):
-            base_i = jnp.broadcast_to(jnp.asarray(base_rows[i])[None], (p, v - s, n_in))
-            tiles = jnp.concatenate([base_i, clus_j], axis=1)            # (P, V, n_in)
-            ret = np.asarray(_tile_retained(tiles, cfg, cost_mode))
-            cost[i, :] = totals[i] - ret
+        ret = np.asarray(_assignment_retained(
+            jnp.asarray(base_rows), jnp.asarray(clus_rows), cfg, cost_mode))
+        cost = totals.astype(np.float64) - ret
         rows, cols = linear_sum_assignment(cost)
 
         new_part = np.concatenate([bases, clusters[cols]], axis=1)       # (P, V)
@@ -218,70 +239,107 @@ def _icp_cost_matrix(
     return totals - ret
 
 
-def icp_tile(
-    tile: np.ndarray,
-    cfg: HiNMConfig,
-    iters: int = 16,
-    patience: int = 4,
-) -> tuple[np.ndarray, list[float]]:
-    """Permute the K kept columns of one (V, K) tile. Returns (order, hist)."""
-    tile = np.asarray(tile, dtype=np.float32)
-    v, k = tile.shape
-    g = k // cfg.m
-    order = np.arange(k)
+@functools.partial(jax.jit, static_argnames=("n", "m"))
+def _icp_step(tiles: jax.Array, orders: jax.Array, n: int, m: int):
+    """One ICP sampling step for every tile, in one dispatch.
 
-    def retained(o: np.ndarray) -> float:
-        grp = jnp.asarray(tile[:, o].reshape(v, g, cfg.m))
-        return float(_nm_retained_groups(jnp.moveaxis(grp, 0, 1), cfg.n, cfg.m).sum())
+    tiles (T, V, K), orders (T, K) -> (extract_slot (T, G), cost (T, G, G)):
+    the most replaceable column of each partition under the current order,
+    and the Eq.(4) cost of placing extracted column j into partition i."""
 
-    best = retained(order)
-    history = [best]
-    if g == 1:
-        return order, history
-    stall = 0
-    for _ in range(iters):
-        cur = jnp.asarray(tile[:, order])
-        marg = np.asarray(_icp_marginals(cur, cfg.n, cfg.m))              # (G, M)
-        extract_slot = np.argmin(marg, axis=1)                            # (G,)
-        pos = order.reshape(g, cfg.m)
-        extracted_pos = np.take_along_axis(pos, extract_slot[:, None], axis=1)[:, 0]
-        keep = np.ones((g, cfg.m), dtype=bool)
-        np.put_along_axis(keep, extract_slot[:, None], False, axis=1)
-        rem_pos = pos[keep].reshape(g, cfg.m - 1)
+    def one(tile, order):
+        v, k = tile.shape
+        g = k // m
+        cur = jnp.take(tile, order, axis=1)
+        slot = jnp.argmin(_icp_marginals(cur, n, m), axis=1)          # (G,)
+        pos = order.reshape(g, m)
+        keep = jnp.arange(m)[None, :] != slot[:, None]                # (G, M)
+        rem_pos = jnp.sort(jnp.where(keep, pos, k), axis=1)[:, :m - 1]
+        ext_pos = jnp.take_along_axis(pos, slot[:, None], axis=1)[:, 0]
+        rem = jnp.moveaxis(tile[:, rem_pos.reshape(-1)].reshape(v, g, m - 1),
+                           0, 1)                                       # (G, V, M-1)
+        return slot, _icp_cost_matrix(rem, tile[:, ext_pos].T, n, m)
 
-        rem = jnp.asarray(tile[:, rem_pos.reshape(-1)].reshape(v, g, cfg.m - 1))
-        rem = jnp.moveaxis(rem, 0, 1)                                      # (G, V, M-1)
-        cols = jnp.asarray(tile[:, extracted_pos]).T                       # (G, V)
-        cost = np.asarray(_icp_cost_matrix(rem, cols, cfg.n, cfg.m))
-        _, assign = linear_sum_assignment(cost)
+    return jax.lax.map(lambda a: one(*a), (tiles, orders))
 
-        new_pos = np.concatenate([rem_pos, extracted_pos[assign][:, None]], axis=1)
-        new_order = new_pos.reshape(-1)
-        cand = retained(new_order)
-        if cand > best + 1e-9:
-            best, order = cand, new_order
-            stall = 0
-        else:
-            stall += 1
-        history.append(best)
-        if stall >= patience:
-            break
-    return order, history
+
+@functools.partial(jax.jit, static_argnames=("n", "m"))
+def _icp_retained(tiles: jax.Array, orders: jax.Array, n: int, m: int):
+    """(T,) retained saliency of each tile grouped in its column order."""
+
+    def one(tile, order):
+        v, k = tile.shape
+        grp = jnp.take(tile, order, axis=1).reshape(v, k // m, m)
+        return _nm_retained_groups(jnp.moveaxis(grp, 0, 1), n, m).sum()
+
+    return jax.lax.map(lambda a: one(*a), (tiles, orders))
 
 
 def icp(
     sal_gathered: np.ndarray,
     cfg: HiNMConfig,
     iters: int = 16,
+    patience: int = 4,
+) -> tuple[np.ndarray, np.ndarray]:
+    """Permute the K kept columns of every (V, K) tile, all tiles at once.
+
+    sal_gathered (T, V, K) -> (orders (T, K), history (n_iters + 1, T) of
+    each tile's best retained saliency). Each iteration extracts the most
+    replaceable column of every partition (the ICP sample) and reassigns
+    the extracted columns by Hungarian matching; a tile keeps the new order
+    only when it retains more, and stops after `patience` stalls. The
+    device computes every tile's costs in one dispatch per step; the
+    assignments are solved per tile on the host.
+    """
+    tiles_np = np.asarray(sal_gathered, dtype=np.float32)
+    t, v, k = tiles_np.shape
+    g = k // cfg.m
+    tiles = jnp.asarray(tiles_np)
+    orders = np.tile(np.arange(k), (t, 1))
+    best = np.array(_icp_retained(tiles, jnp.asarray(orders), cfg.n, cfg.m))
+    history = [best.copy()]
+    if g == 1:
+        return orders, np.stack(history)
+    stall = np.zeros(t, dtype=np.int64)
+    for _ in range(iters):
+        live = np.flatnonzero(stall < patience)
+        if live.size == 0:
+            break
+        # every tile is costed (fixed shapes: one compile); only the live
+        # ones are reassigned
+        slot, cost = _icp_step(tiles, jnp.asarray(orders), cfg.n, cfg.m)
+        slot, cost = np.asarray(slot), np.asarray(cost)
+        cand = orders.copy()
+        for ti in live:
+            pos = orders[ti].reshape(g, cfg.m)
+            ext = pos[np.arange(g), slot[ti]]
+            keep = np.ones((g, cfg.m), dtype=bool)
+            keep[np.arange(g), slot[ti]] = False
+            rem_pos = pos[keep].reshape(g, cfg.m - 1)
+            _, assign = linear_sum_assignment(cost[ti])
+            cand[ti] = np.concatenate([rem_pos, ext[assign][:, None]],
+                                      axis=1).reshape(-1)
+        ret = np.asarray(_icp_retained(tiles, jnp.asarray(cand), cfg.n, cfg.m))
+        better = np.zeros(t, dtype=bool)
+        better[live] = ret[live] > best[live] + 1e-9
+        orders[better] = cand[better]
+        best[better] = ret[better]
+        stall[better] = 0
+        stall[live[~better[live]]] += 1
+        history.append(best.copy())
+    return orders, np.stack(history)
+
+
+def icp_tile(
+    tile: np.ndarray,
+    cfg: HiNMConfig,
+    iters: int = 16,
+    patience: int = 4,
 ) -> tuple[np.ndarray, list[float]]:
-    """Run ICP on every tile. sal_gathered: (T, V, K) -> orders (T, K)."""
-    t = sal_gathered.shape[0]
-    orders = np.empty((t, sal_gathered.shape[2]), dtype=np.int64)
-    history: list[float] = []
-    for ti in range(t):
-        orders[ti], h = icp_tile(sal_gathered[ti], cfg, iters=iters)
-        history.append(h[-1])
-    return orders, history
+    """ICP on one (V, K) tile. Returns (order, history)."""
+    orders, history = icp(np.asarray(tile)[None], cfg, iters=iters,
+                          patience=patience)
+    return orders[0], [float(h) for h in history[:, 0]]
 
 
 # ---------------------------------------------------------------------------

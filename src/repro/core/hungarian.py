@@ -105,9 +105,11 @@ def balanced_kmeans(
     # k-means++ style init
     centroids = points[rng.choice(n_pts, size=n_clusters, replace=False)]
     labels = np.zeros(n_pts, dtype=np.int64)
+    sq = (points ** 2).sum(-1)
     for _ in range(n_iters):
         # squared distances (P, C)
-        d2 = ((points[:, None, :] - centroids[None, :, :]) ** 2).sum(-1)
+        d2 = (sq[:, None] - 2.0 * points @ centroids.T
+              + (centroids ** 2).sum(-1)[None, :])
         slot_cost = np.repeat(d2, cap, axis=1)  # (P, C*cap)
         _, cols = linear_sum_assignment(slot_cost)
         new_labels = cols // cap

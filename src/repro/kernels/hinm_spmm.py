@@ -1,29 +1,41 @@
 """Pallas TPU kernel: HiNM sparse matmul  y = x @ W_packed^T.
 
-TPU adaptation of the paper's SpMM (DESIGN.md §2, §5). Per grid cell
-(one output tile x one batch block):
+TPU adaptation of the paper's SpMM. Per grid cell (one output tile x one
+batch block):
 
-  1. *Indexed gather* — the tile's `vec_idx` row (VMEM, int32) selects the
-     K kept input channels out of the (n_in, Bblk) activation block resident
-     in VMEM. This is the TPU analogue of the paper's global->shared indexed
-     load: a permuted `vec_idx` (the ICP order) costs exactly the same as an
-     identity one, so the runtime input-channel reorder is free.
-  2. *In-VMEM N:M decompression* — packed values (V, Kn) are expanded
-     against their 2-bit slot indices to a dense (V, K) tile with a
-     one-hot-compare contraction on the VPU (the STC-metadata equivalent;
-     TPU has no sparse MXU so the N:M level buys bandwidth, not FLOPs).
-  3. *Dense MXU contraction* — (V, K) @ (K, Bblk) accumulated in f32.
+  1. *Indexed gather* — the tile's `vec_idx` row sits in SMEM and drives
+     one dynamic-offset row load per kept column out of the (n_in, Bblk)
+     activation block resident in VMEM. This is the TPU analogue of the
+     paper's global->shared indexed load: a permuted `vec_idx` (the ICP
+     order) costs exactly the same as an identity one, so the runtime
+     input-channel reorder is free.
+  2. *N:M slot split instead of a decompress* — the gathered rows land in
+     M "slot planes": plane j row p holds the activation row of dense
+     column ``(p // N) * M + j``, i.e. the column that packed value p
+     occupies when its 2-bit slot index is j. The packed values then
+     contract against the planes as they are stored, masked per plane:
 
-Layouts: activations enter as xT (n_in, B) so the gather runs on the
-sublane axis; outputs leave as (n_out, B) with rows in packed (OCP) order.
+         y = sum_j where(nm_idx == j, vals, 0) @ plane_j
 
-VMEM budget per cell (defaults V=32, Bblk=256, bf16; see `pick_bblk`):
-  xT block    n_in*Bblk*2   (e.g. 5120*256*2 = 2.5 MiB)
-  gather      K*Bblk*2      (jnp.take stays in the activation dtype)
-  weights     V*Kn*3 + K*4  (vals + int8 slot indices + vec_idx row)
-  decompress  V*Kn*M*2 one-hot transient + V*K*2 dense tile
-  accum       V*Bblk*4      (f32)
-comfortably inside 16 MiB VMEM with double buffering.
+     Nothing is reshaped across the lane axis (Mosaic refuses lane-
+     splitting reshapes), and the contraction touches M * Kn = N * K rows,
+     the dense layer's n_in at 50 % vector sparsity. TPU has no sparse
+     MXU, so the N:M level buys HBM bandwidth, not FLOPs.
+  3. *Dense MXU contraction* — each plane contraction runs in the storage
+     dtype on the MXU with f32 accumulation.
+
+Layouts: activations enter as xT (n_in, B) in f32 (the dynamic single-row
+load needs a 32-bit sublane layout; the values are the bf16 activations
+exactly) so the gather runs on the sublane axis; `vec_idx` enters as
+(T, 1, K) int32 in SMEM; outputs leave as (n_out, B) with rows in packed
+(OCP) order.
+
+VMEM budget per cell (see `pick_bblk`):
+  xT block     n_in*Bblk*4 (double-buffered)
+  slot planes  M*Kn8*Bblk*4 scratch (Kn8 = Kn rounded up to 8 rows)
+  weights      V*Kn8*(itemsize + 1) double-buffered (vals + int8 slots)
+  masked vals  V*Kn8*itemsize transient per plane
+  accum/out    V*Bblk*4 + V*Bblk*itemsize (double-buffered)
 """
 from __future__ import annotations
 
@@ -32,65 +44,76 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+LANES = 128
 DEFAULT_BBLK = 256
 VMEM_BUDGET_BYTES = 8 * 1024 * 1024  # conservative half of v5e VMEM
 
 
-def _kernel(x_ref, vals_ref, nm_ref, idx_ref, out_ref, *, nn: int, mm: int):
-    idx = idx_ref[0]                                  # (K,) int32
-    xg = jnp.take(x_ref[...], idx, axis=0)            # (K, Bblk) sublane gather
-    vals = vals_ref[0]                                # (V, Kn)
-    slots = nm_ref[0].astype(jnp.int32)               # (V, Kn)
-    v, kn = vals.shape
-    g = kn // nn
-    v4 = vals.reshape(v, g, nn)
-    s4 = slots.reshape(v, g, nn)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (v, g, nn, mm), 3)
-    w = (v4[..., None] * (iota == s4[..., None]).astype(vals.dtype)).sum(axis=2)
-    w = w.reshape(v, g * mm)                          # (V, K) dense tile
-    # inputs stay in the storage dtype (bf16 feeds the MXU natively; an
-    # explicit f32 upcast would double the gather + tile VMEM footprint
-    # that pick_bblk budgets); accumulation is f32 via preferred_element_type
-    ct = jnp.promote_types(w.dtype, xg.dtype)
-    acc = jax.lax.dot_general(
-        w.astype(ct),
-        xg.astype(ct),
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+def _kernel(idx_ref, x_ref, vals_ref, nm_ref, out_ref, planes_ref, *,
+            nn: int, mm: int, k: int, ct):
+    kn8 = vals_ref.shape[-1]
+
+    def gather(q, carry):
+        # 8 plane rows p = 8q .. 8q+7 per iteration; rows of one N-group
+        # share their source column, so each is loaded once and repeated
+        base = pl.multiple_of(q * 8, 8)
+        for j in range(mm):
+            rows = []
+            for s in range(8):
+                if 8 % nn == 0 and s % nn:
+                    rows.append(rows[-1])
+                    continue
+                col = jnp.minimum((base + s) // nn * mm + j, k - 1)
+                rows.append(x_ref[pl.ds(idx_ref[0, 0, col], 1), :])
+            planes_ref[j, pl.ds(base, 8), :] = jnp.concatenate(rows, axis=0)
+        return carry
+
+    jax.lax.fori_loop(0, kn8 // 8, gather, 0)
+
+    vals = vals_ref[0]                                 # (V, Kn8)
+    slots = nm_ref[0].astype(jnp.int32)                # (V, Kn8)
+    acc = None
+    for j in range(mm):
+        part = jax.lax.dot_general(
+            jnp.where(slots == j, vals, jnp.zeros_like(vals)).astype(ct),
+            planes_ref[j].astype(ct),
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )                                              # (V, Bblk)
+        acc = part if acc is None else acc + part
     out_ref[...] = acc.astype(out_ref.dtype)
 
 
 def pick_bblk(n_in: int, k: int, b: int, itemsize: int = 2, *, v: int = 32,
               nn: int = 2, mm: int = 4) -> int:
-    """Largest batch block keeping the VMEM working set under budget.
+    """Batch (lane) block for the kernel.
 
-    Working set per grid cell, with real itemsizes (the gather copy from
-    ``jnp.take`` stays in the activation dtype — it is NOT a 4-byte f32
-    copy — and the in-VMEM N:M decompress materialises a one-hot
-    ``(V, G, N, M)`` transient plus the dense ``(V, K)`` tile):
+    A batch of at most 128 columns is one block spanning the whole lane
+    axis (a block equal to the array's last dim is always tileable).
+    Larger batches take a multiple of 128 lanes: the largest of 256 / 128
+    whose working set stays under the VMEM budget, never below 128. The
+    wrapper pads the batch to a multiple of the block.
 
-      xT block      n_in * bblk * itemsize
-      gather copy   k * bblk * itemsize
-      weights       v*kn*(itemsize + 1) + k*4   (vals + int8 slots + vec_idx)
-      decompress    v*kn*mm*itemsize + v*k*itemsize
-      f32 accum     v * bblk * 4
+    Working set per grid cell (real itemsizes; the activation block is the
+    f32 copy the gather reads):
 
-    Only the first two and the accumulator scale with bblk; the weight and
-    decompress terms are a fixed per-cell cost subtracted from the budget.
-    The halving search itself is the shared ``ops.pick_tile`` (batch blocks
-    need no divisibility — the wrapper pads the remainder).
+      xT block      2 * n_in * bblk * 4        (double-buffered)
+      slot planes   mm * kn8 * bblk * 4
+      weights       2 * v * kn8 * (itemsize + 1) + v * kn8 * itemsize
+      accum / out   v * bblk * 4 + 2 * v * bblk * itemsize
     """
     from repro.kernels import ops
 
-    kn = k // mm * nn
-    fixed = (v * kn * (itemsize + 1) + k * 4
-             + v * kn * mm * itemsize + v * k * itemsize)
-    per_col = (n_in + k) * itemsize + v * 4
-    bblk = ops.pick_tile(DEFAULT_BBLK, fixed, per_col,
-                         budget=VMEM_BUDGET_BYTES, floor=8, divide=False)
-    return max(8, min(bblk, max(8, b)))
+    if b <= LANES:
+        return b
+    kn8 = -(-(k // mm * nn) // 8) * 8
+    fixed = 3 * v * kn8 * (itemsize + 1)
+    per_col = 2 * n_in * 4 + mm * kn8 * 4 + v * (4 + 2 * itemsize)
+    tile = ops.pick_tile(DEFAULT_BBLK // LANES, fixed, per_col * LANES,
+                         budget=VMEM_BUDGET_BYTES, floor=1, divide=False)
+    return tile * LANES
 
 
 @functools.partial(
@@ -115,24 +138,31 @@ def hinm_spmm(
     if kn != k // mm * nn:
         raise ValueError(f"Kn={kn} inconsistent with K={k}, {nn}:{mm}")
     out_dtype = out_dtype or x_t.dtype
-    bblk = bblk or pick_bblk(n_in, k, b, jnp.dtype(x_t.dtype).itemsize,
+    bblk = bblk or pick_bblk(n_in, k, b, jnp.dtype(vals.dtype).itemsize,
                              v=v, nn=nn, mm=mm)
-    if b % bblk != 0:
-        pad = bblk - b % bblk
-        x_t = jnp.pad(x_t, ((0, 0), (0, pad)))
+    ct = jnp.promote_types(vals.dtype, x_t.dtype)
+    x_t = x_t.astype(jnp.float32)
+    if b % bblk:
+        x_t = jnp.pad(x_t, ((0, 0), (0, bblk - b % bblk)))
     bp = x_t.shape[1]
+    kn8 = -(-kn // 8) * 8
+    if kn8 != kn:  # zero values contribute nothing, whatever row they read
+        vals = jnp.pad(vals, ((0, 0), (0, 0), (0, kn8 - kn)))
+        nm_idx = jnp.pad(nm_idx, ((0, 0), (0, 0), (0, kn8 - kn)))
 
     out = pl.pallas_call(
-        functools.partial(_kernel, nn=nn, mm=mm),
+        functools.partial(_kernel, nn=nn, mm=mm, k=k, ct=ct),
         grid=(t, bp // bblk),
         in_specs=[
+            pl.BlockSpec((1, 1, k), lambda i, j: (i, 0, 0),
+                         memory_space=pltpu.SMEM),
             pl.BlockSpec((n_in, bblk), lambda i, j: (0, j)),
-            pl.BlockSpec((1, v, kn), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, v, kn), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, k), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, v, kn8), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, v, kn8), lambda i, j: (i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((v, bblk), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((t * v, bp), out_dtype),
+        scratch_shapes=[pltpu.VMEM((mm, kn8, bblk), jnp.float32)],
         interpret=interpret,
-    )(x_t, vals, nm_idx, vec_idx)
+    )(vec_idx.astype(jnp.int32).reshape(t, 1, k), x_t, vals, nm_idx)
     return out[:, :b]

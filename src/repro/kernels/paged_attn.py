@@ -6,20 +6,25 @@ The serving hot path resolves a slot's KV rows through its block table
 view first (``pool[bt]`` gather: O(n_bt * page) rows copied per step, per
 layer) and then runs chunked online-softmax attention over the copy.  This
 kernel fuses the two: the grid walks the block table directly, one program
-per (slot, kv-head) streaming that slot's pages HBM->VMEM via
-scalar-prefetched index maps, with flash-style online-softmax accumulation
-in VMEM scratch — the contiguous view is never built.
+per slot streaming that slot's pages HBM->VMEM via scalar-prefetched index
+maps, with flash-style online-softmax accumulation per KV head in VMEM
+scratch — the contiguous view is never built.
 
-Grid ``(B, KV, n_bt // pp)``: the last (innermost) dimension streams the
-slot's block-table entries, ``pp`` pages per step.  ``pp`` is picked with
-the same VMEM-budget discipline as ``hinm_spmm.pick_bblk`` (see
-``ops.pick_tile``): the per-page working set (k/v blocks + f32 upcasts +
-score tile) is halved against the budget, so arbitrarily large pages or
-head dims degrade to fewer pages per step instead of spilling VMEM.  Each
-page is fetched by an index map that reads ``bt[b, i*pp + j]`` from SMEM
-(``PrefetchScalarGridSpec``) — a permuted block table costs exactly the
-same as an identity one, the paper's indexed-gather trick applied to the
-KV cache.
+Grid ``(B, n_bt // pp)``: the last (innermost) dimension streams the
+slot's block-table entries, ``pp`` pages per step.  Each page arrives as
+one whole ``(page, KV, hd)`` block (a block's two minor dims must tile or
+span the array, so a page is never split by head) and every KV head of
+the slot is served from it.  ``pp`` is picked with the same VMEM-budget
+discipline as ``hinm_spmm.pick_bblk`` (see ``ops.pick_tile``): the
+per-page working set (padded k/v blocks + f32 upcasts + score tile) is
+halved against the budget, so large pages or head dims degrade to fewer
+pages per step instead of spilling VMEM.  Each page is fetched by an index
+map that reads ``bt[b, i*pp + j]`` from SMEM (``PrefetchScalarGridSpec``)
+— a permuted block table costs exactly the same as an identity one, the
+paper's indexed-gather trick applied to the KV cache.  The slots' kpos
+rows are resolved through the block table outside the kernel (int32s for
+the batch's pages only) and enter as ``(B, n_bt, 1, page)`` so each page's
+row is a tileable block.
 
 Masking folds every paged-pool invariant into one comparison chain:
 
@@ -49,15 +54,16 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+LANES = 128
 
 
-def _kernel(bt_ref, q_ref, qpos_ref, *refs, pp: int, window: int):
+def _kernel(bt_ref, q_ref, qpos_ref, kpos_ref, *refs, pp: int, window: int):
     k_refs = refs[:pp]
     v_refs = refs[pp:2 * pp]
-    p_refs = refs[2 * pp:3 * pp]
-    o_ref = refs[3 * pp]
-    m_ref, l_ref, acc_ref = refs[3 * pp + 1:]
-    i = pl.program_id(2)
+    o_ref = refs[2 * pp]
+    m_ref, l_ref, acc_ref = refs[2 * pp + 1:]
+    kvh = q_ref.shape[1]
+    i = pl.program_id(1)
 
     @pl.when(i == 0)
     def _init():
@@ -65,50 +71,63 @@ def _kernel(bt_ref, q_ref, qpos_ref, *refs, pp: int, window: int):
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0]                                   # (Gs, hd) f32 pre-scaled
-    qpos = qpos_ref[0]                                # (Gs,) int32
+    qpos = qpos_ref[0]                                # (Gs, 1) int32
     for j in range(pp):
-        kj = k_refs[j][0, :, 0, :].astype(jnp.float32)    # (page, hd)
-        vj = v_refs[j][0, :, 0, :].astype(jnp.float32)
-        kp = p_refs[j][0]                                 # (page,) int32
-        s = jax.lax.dot_general(
-            q, kj, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)           # (Gs, page)
-        msk = kp[None, :] <= qpos[:, None]
+        kp = kpos_ref[0, j]                           # (1, page) int32
+        msk = kp <= qpos
         if window:
-            msk &= kp[None, :] > qpos[:, None] - window
-        s = jnp.where(msk, s, NEG_INF)
-        m_prev = m_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_ref[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p, vj, dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+            msk &= kp > qpos - window
+        for h in range(kvh):
+            q = q_ref[0, h]                           # (Gs, hd) f32 pre-scaled
+            kj = k_refs[j][0, :, h, :].astype(jnp.float32)   # (page, hd)
+            vj = v_refs[j][0, :, h, :].astype(jnp.float32)
+            s = jax.lax.dot_general(
+                q, kj, dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)           # (Gs, page)
+            s = jnp.where(msk, s, NEG_INF)
+            m_prev = m_ref[h][:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_new = l_ref[h][:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
+                p, vj, dimension_numbers=(((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
 
-    @pl.when(i == pl.num_programs(2) - 1)
+    @pl.when(i == pl.num_programs(1) - 1)
     def _fin():
-        o_ref[0, 0] = (acc_ref[...]
-                       / jnp.maximum(l_ref[:, :1], 1e-30)).astype(o_ref.dtype)
+        for h in range(kvh):
+            o_ref[0, h] = (acc_ref[h] / jnp.maximum(l_ref[h][:, :1], 1e-30)
+                           ).astype(o_ref.dtype)
 
 
-def pick_pp(n_bt: int, page: int, hd: int, gs: int, itemsize: int) -> int:
+def _vmem_bytes(rows: int, cols: int, itemsize: int) -> int:
+    """VMEM bytes of a (rows, cols) tile after (sublane, 128-lane) padding;
+    a 32-bit sublane tile holds 8 rows, packed dtypes proportionally more."""
+    sub = 8 * max(1, 4 // itemsize)
+    return -(-rows // sub) * sub * -(-cols // LANES) * LANES * itemsize
+
+
+def pick_pp(n_bt: int, page: int, hd: int, gs: int, itemsize: int,
+            kvh: int = 1) -> int:
     """Pages streamed per grid step, VMEM-budgeted like ``pick_bblk``.
 
-    Per-page working set: the k/v blocks in storage dtype, their f32
-    upcasts, the kpos row, and the (Gs, page) score/probability transients.
-    Fixed per-program cost: the pre-scaled q tile, the f32 accumulator and
-    output tile, and the (Gs, 128) m/l statistic scratch.
+    Per-page working set: the double-buffered (page, KV, hd) k/v blocks in
+    storage dtype, padded to the VMEM tiling (the KV dim sits on the
+    sublane axis), the kpos row, and per head the f32 upcasts plus the
+    (Gs, page) score / probability / mask transients. Fixed per-program
+    cost: the pre-scaled q tile, the f32 accumulator and output tile, and
+    the (Gs, 128) m/l statistic scratch, all per KV head.
     """
     from repro.kernels import ops
 
-    fixed = gs * hd * 4 * 3 + gs * 128 * 4 * 2 + gs * 4
-    per_page = (page * hd * (itemsize + 4) * 2   # k/v blocks + f32 upcasts
-                + page * 4                       # kpos row
-                + gs * page * 4 * 3)             # scores / probs / mask
+    fixed = kvh * (3 * _vmem_bytes(gs, hd, 4) + 2 * _vmem_bytes(gs, LANES, 4))
+    per_page = (2 * 2 * page * _vmem_bytes(kvh, hd, itemsize)
+                + 2 * _vmem_bytes(1, page, 4)
+                + 2 * _vmem_bytes(page, hd, 4)
+                + 3 * _vmem_bytes(gs, page, 4))
     return ops.pick_tile(n_bt, fixed, per_page, start=8)
 
 
@@ -131,37 +150,39 @@ def paged_decode_attn(
     g = h // kvh
     gs = s * g
     n_bt = bt.shape[1]
-    pp = pp or pick_pp(n_bt, page, hd, gs, jnp.dtype(k_pool.dtype).itemsize)
+    pp = pp or pick_pp(n_bt, page, hd, gs, jnp.dtype(k_pool.dtype).itemsize,
+                       kvh)
 
     scale = hd ** -0.5
     # row layout (s, G) flattened s-major: row r belongs to query s-index
     # r // G, so its position is q_pos repeated G times along the row axis
     qf = (q.astype(jnp.float32) * scale).reshape(b, s, kvh, g, hd)
     qf = jnp.moveaxis(qf, 2, 1).reshape(b, kvh, gs, hd)
-    qpos = jnp.repeat(q_pos.astype(jnp.int32), g, axis=1)     # (B, Gs)
+    qpos = jnp.repeat(q_pos.astype(jnp.int32), g, axis=1)[..., None]
+    bt = bt.astype(jnp.int32)
+    # the slots' kpos rows, resolved through the block table (B*n_bt*page
+    # int32s — the batch's pages, not the pool), one (1, page) row per page
+    kpos_view = jnp.take(kpos_pool, bt, axis=0)[:, :, None, :]
 
     def pool_map(j):
-        return lambda bi, hi, ii, tbl: (tbl[bi, ii * pp + j], 0, hi, 0)
-
-    def kpos_map(j):
-        return lambda bi, hi, ii, tbl: (tbl[bi, ii * pp + j], 0)
+        return lambda bi, ii, tbl: (tbl[bi, ii * pp + j], 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, kvh, n_bt // pp),
+        grid=(b, n_bt // pp),
         in_specs=(
-            [pl.BlockSpec((1, 1, gs, hd), lambda bi, hi, ii, tbl: (bi, hi, 0, 0)),
-             pl.BlockSpec((1, gs), lambda bi, hi, ii, tbl: (bi, 0))]
-            + [pl.BlockSpec((1, page, 1, hd), pool_map(j)) for j in range(pp)]
-            + [pl.BlockSpec((1, page, 1, hd), pool_map(j)) for j in range(pp)]
-            + [pl.BlockSpec((1, page), kpos_map(j)) for j in range(pp)]
+            [pl.BlockSpec((1, kvh, gs, hd), lambda bi, ii, tbl: (bi, 0, 0, 0)),
+             pl.BlockSpec((1, gs, 1), lambda bi, ii, tbl: (bi, 0, 0)),
+             pl.BlockSpec((1, pp, 1, page), lambda bi, ii, tbl: (bi, ii, 0, 0))]
+            + [pl.BlockSpec((1, page, kvh, hd), pool_map(j)) for j in range(pp)]
+            + [pl.BlockSpec((1, page, kvh, hd), pool_map(j)) for j in range(pp)]
         ),
-        out_specs=pl.BlockSpec((1, 1, gs, hd),
-                               lambda bi, hi, ii, tbl: (bi, hi, 0, 0)),
+        out_specs=pl.BlockSpec((1, kvh, gs, hd),
+                               lambda bi, ii, tbl: (bi, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((gs, 128), jnp.float32),   # running max m
-            pltpu.VMEM((gs, 128), jnp.float32),   # running sum l
-            pltpu.VMEM((gs, hd), jnp.float32),    # output accumulator
+            pltpu.VMEM((kvh, gs, LANES), jnp.float32),   # running max m
+            pltpu.VMEM((kvh, gs, LANES), jnp.float32),   # running sum l
+            pltpu.VMEM((kvh, gs, hd), jnp.float32),      # output accumulator
         ],
     )
     out = pl.pallas_call(
@@ -169,8 +190,7 @@ def paged_decode_attn(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, gs, hd), jnp.float32),
         interpret=interpret,
-    )(bt.astype(jnp.int32), qf, qpos,
-      *([k_pool] * pp), *([v_pool] * pp), *([kpos_pool] * pp))
+    )(bt, qf, qpos, kpos_view, *([k_pool] * pp), *([v_pool] * pp))
 
     out = jnp.moveaxis(out.reshape(b, kvh, s, g, hd), 1, 2)
     return out.reshape(b, s, h, hd).astype(q.dtype)

@@ -105,10 +105,8 @@ def hinm_spmm_shard_map(x: jax.Array, p: PackedHiNM) -> jax.Array | None:
     Returns None when preconditions don't hold (no mesh context, tile or
     batch dims don't divide) — caller falls back to the XLA path.
     """
-    from repro import compat
-
-    am = compat.get_abstract_mesh()
-    if am is None or am.empty or "model" in getattr(am, "manual_axes", ()):
+    am = jax.sharding.get_abstract_mesh()
+    if am.empty or "model" in getattr(am, "manual_axes", ()):
         return None
     if "model" not in am.axis_names:
         return None
@@ -130,12 +128,13 @@ def hinm_spmm_shard_map(x: jax.Array, p: PackedHiNM) -> jax.Array | None:
     def body(xl, vl, nl, il):
         return _gather_matmul(xl, il, vl, nl, cfg.m, cfg.n, x.dtype)
 
-    y = compat.shard_map(
+    y = jax.shard_map(
         body,
         mesh=am,
         in_specs=(P(row_spec, None), P("model", None, None),
                   P("model", None, None), P("model", None)),
         out_specs=P(row_spec, "model", None),
+        check_vma=False,
     )(x, p.vals, p.nm_idx, p.vec_idx)
     return y.reshape(b, p.n_out)
 

@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import SHAPES, ArchConfig
 from repro.data.pipeline import make_batch_specs
 from repro.distributed import sharding as shd
@@ -181,7 +180,7 @@ def build_cell(cfg: ArchConfig, shape_name: str, mesh,
     if skip:
         return Cell(cfg.name, shape_name, SHAPES[shape_name][2], None, (), skipped=skip)
     kind = SHAPES[shape_name][2]
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if kind == "train":
             return build_train_cell(cfg, shape_name, mesh, shape_override)
         if kind == "prefill":
@@ -190,5 +189,5 @@ def build_cell(cfg: ArchConfig, shape_name: str, mesh,
 
 
 def lower_cell(cell: Cell, mesh):
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         return cell.jitted.lower(*cell.args)

@@ -11,17 +11,24 @@ from __future__ import annotations
 
 import jax
 
-from repro import compat
+
+
+def make_mesh(axis_shapes, axis_names):
+    """``jax.make_mesh`` with Auto axes: sharding is propagated by XLA and
+    pinned by ``with_sharding_constraint`` (JAX's own default is Explicit)."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
     """Small mesh over whatever devices exist (tests / local runs)."""
     n = len(jax.devices())
     data = max(1, n // model)
-    return compat.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))

@@ -14,8 +14,6 @@ import argparse
 import logging
 
 import jax
-
-from repro import compat
 import jax.numpy as jnp
 import numpy as np
 
@@ -83,7 +81,7 @@ def main() -> None:
         checkpoint_every=max(args.steps // 4, 25),
         checkpoint_dir=args.checkpoint_dir,
     )
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         final = loop.run(state, jitted, batch_iter(), lcfg)
     print(f"done at step {final.step}")
 

@@ -47,10 +47,8 @@ def _seq_parallel_decode_attn(q, ck, cv, q_pos, kpos, window: int):
     O(B*H*hd) bytes instead of all-gathering the cache (GBs per layer).
     Returns None when preconditions fail (no mesh / S doesn't divide).
     """
-    from repro import compat
-
-    am = compat.get_abstract_mesh()
-    if am is None or am.empty or "model" not in am.axis_names:
+    am = jax.sharding.get_abstract_mesh()
+    if am.empty or "model" not in am.axis_names:
         return None
     b, sq, h, hd = q.shape
     smax, kv = ck.shape[1], ck.shape[2]

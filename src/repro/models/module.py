@@ -141,10 +141,8 @@ def constrain(x: jax.Array, roles: tuple) -> jax.Array:
     checks; silently no-ops without a mesh context (CPU smoke tests) and
     degrades any non-divisible dim to replicated.
     """
-    from repro import compat
-
-    am = compat.get_abstract_mesh()
-    if am is None or am.empty:
+    am = jax.sharding.get_abstract_mesh()
+    if am.empty:
         return x
     dp = tuple(a for a in ("pod", "data") if a in am.axis_names)
     spec = []

@@ -56,6 +56,7 @@ actually gates on free pages; pass `None` for full stripe capacity.
 from __future__ import annotations
 
 import collections
+import contextlib
 import os
 import time
 import warnings
@@ -378,6 +379,21 @@ class Scheduler:
 
     # -- jitted kernels -----------------------------------------------------
 
+    @contextlib.contextmanager
+    def _trace_ctx(self):
+        """Trace-time context of every jitted model forward: the resolved
+        paged-attention backend and, with a mesh, that mesh as the abstract
+        mesh — the compiled Pallas kernels are not auto-partitioned, so
+        ``ops`` runs them per device in a ``shard_map`` over it."""
+        from repro.perf_knobs import knobs
+
+        with knobs(paged_attn=self.paged_attn):
+            if self.mesh is None:
+                yield
+            else:
+                with jax.sharding.use_abstract_mesh(self.mesh.abstract_mesh):
+                    yield
+
     def _build(self) -> None:
         cfg, vocab, chunk = self.cfg, self._vocab, self.decode_chunk
 
@@ -390,9 +406,11 @@ class Scheduler:
         def prefill_fn(params, tokens, cache, embeds, base_key, seeds, temp,
                        topk, topp, n_rows, stochastic):
             self._m_prefill_traces.inc()  # runs at trace time only
-            last, cache = zoo.prefill(params, cfg, tokens, cache,
-                                      embeds=embeds, n_rows=n_rows)
-            logits = zoo.logits_fn(params, cfg, last)[:, :vocab].astype(jnp.float32)
+            with self._trace_ctx():
+                last, cache = zoo.prefill(params, cfg, tokens, cache,
+                                          embeds=embeds, n_rows=n_rows)
+                logits = zoo.logits_fn(params, cfg, last)[:, :vocab]
+            logits = logits.astype(jnp.float32)
             if stochastic:
                 keys = sampler.fold_keys(base_key, seeds,
                                          jnp.zeros_like(seeds))
@@ -431,9 +449,7 @@ class Scheduler:
                 tok = jnp.where(active, nxt, tok[:, 0])[:, None]
                 return (cache, tok, active, rem, gens), emit
 
-            from repro.perf_knobs import knobs
-
-            with knobs(paged_attn=self.paged_attn):  # applies at trace time
+            with self._trace_ctx():
                 carry, emits = jax.lax.scan(
                     step, (cache, tok, active, rem, gens), None, length=chunk)
             if guarded:
@@ -473,11 +489,9 @@ class Scheduler:
         def verify_fn(params, cache, drafts, tok, active, rem, temp, topk,
                       topp, eos, seeds, gens, keff, match, hist, hlen,
                       base_key, stochastic, any_reject):
-            from repro.perf_knobs import knobs
-
             pos0 = zoo.cache_position(cfg, cache)
             tokens = jnp.concatenate([tok, drafts], axis=1)
-            with knobs(paged_attn=self.paged_attn):  # applies at trace time
+            with self._trace_ctx():
                 logits, cache, undo = zoo.verify_step(params, cfg, tokens,
                                                       cache)
             logits = logits[..., :vocab].astype(jnp.float32)
@@ -572,8 +586,6 @@ class Scheduler:
             def spec_fused_fn(params, cache, tok, active, rem, temp, topk,
                               topp, eos, seeds, gens, keff, match, hist,
                               hlen, base_key, stochastic, any_reject):
-                from repro.perf_knobs import knobs
-
                 def cycle(carry, _):
                     cache, tok, active, rem, gens, hist, hlen = carry
                     drafts = spec_mod.ngram_propose(hist, hlen, tok, k_spec,
@@ -586,7 +598,7 @@ class Scheduler:
                     return ((cache, tok, active, rem, gens, hist, hlen),
                             (emits, cnt, judged))
 
-                with knobs(paged_attn=self.paged_attn):  # trace-time knob
+                with self._trace_ctx():
                     carry, outs = jax.lax.scan(
                         cycle, (cache, tok, active, rem, gens, hist, hlen),
                         None, length=cycles)
@@ -602,8 +614,6 @@ class Scheduler:
                               rem, temp, topk, topp, eos, seeds, gens, keff,
                               match, hist, hlen, base_key, stochastic,
                               any_reject):
-                from repro.perf_knobs import knobs
-
                 def cycle(carry, _):
                     cache, dcache, tok, active, rem, gens, hist, hlen = carry
                     dpos0 = zoo.cache_position(dcfg, dcache)
@@ -629,7 +639,7 @@ class Scheduler:
                     return ((cache, dcache, tok, active, rem, gens, hist,
                              hlen), (emits, cnt, judged))
 
-                with knobs(paged_attn=self.paged_attn):  # trace-time knob
+                with self._trace_ctx():
                     carry, outs = jax.lax.scan(
                         cycle,
                         (cache, dcache, tok, active, rem, gens, hist, hlen),
@@ -661,10 +671,8 @@ class Scheduler:
 
             def extend_fn(params, cache, tokens, keep, base_key, seeds,
                           temp, topk, topp):
-                from repro.perf_knobs import knobs
-
                 pos0 = zoo.cache_position(cfg, cache)
-                with knobs(paged_attn=self.paged_attn):  # trace-time knob
+                with self._trace_ctx():
                     x, cache, undo = zoo.extend_step(params, cfg, tokens,
                                                      cache)
                 cache = zoo.cache_rollback(cfg, cache, undo, pos0, keep,

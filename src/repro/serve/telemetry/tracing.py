@@ -131,11 +131,9 @@ class TraceRecorder:
         context.  Host spans then share names with device-trace slices."""
         if not self.annotate:
             return contextlib.nullcontext()
-        try:
-            from jax import profiler
-        except ImportError:  # pragma: no cover - jax is a hard dep here
-            return contextlib.nullcontext()
-        if step is not None and hasattr(profiler, "StepTraceAnnotation"):
+        from jax import profiler
+
+        if step is not None:
             return profiler.StepTraceAnnotation(name, step_num=step)
         return profiler.TraceAnnotation(name)
 

@@ -38,7 +38,7 @@ try:
 except ImportError:  # `python tests/serve_conformance.py <mode>` driver
     pytest = None
 
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.configs.base import load_arch
 from repro.models import paging, zoo
 from repro.serve import (ModelDrafter, Request, SamplingParams, Scheduler,
@@ -704,7 +704,7 @@ def _sharded_case(mode: str) -> None:
     enough devices (CI multi-device job), else in a subprocess with the
     host-platform device-count flag."""
     if len(jax.devices()) >= N_DEVICES:
-        _drive(mode, compat.make_mesh((N_DEVICES,), ("data",)))
+        _drive(mode, make_mesh((N_DEVICES,), ("data",)))
         return
     # merge with inherited flags, but override any smaller device count
     # (we only reach here when this process has < N_DEVICES devices)
@@ -809,7 +809,7 @@ if pytest is not None:
         run_churn(seed, mesh=None)
         # 1-device mesh: the sharded code path (specs, device_put,
         # constrained writes) without multi-device execution
-        run_churn(seed, mesh=compat.make_mesh((1,), ("data",)))
+        run_churn(seed, mesh=make_mesh((1,), ("data",)))
 
     def test_churn_sharded():
         _sharded_case("churn")
@@ -822,5 +822,5 @@ if __name__ == "__main__":
     _mode = sys.argv[1] if len(sys.argv) > 1 else "conformance:transformer"
     assert len(jax.devices()) >= N_DEVICES, \
         f"{len(jax.devices())} devices; the driver needs the XLA flag"
-    _drive(_mode, compat.make_mesh((N_DEVICES,), ("data",)))
+    _drive(_mode, make_mesh((N_DEVICES,), ("data",)))
     print(f"CONFORMANCE_OK {_mode}")

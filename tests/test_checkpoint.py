@@ -63,9 +63,9 @@ def test_elastic_restore_with_shardings(tmp_path):
     """Restore re-places leaves with the current mesh's shardings."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro import compat
+    from repro.launch.mesh import make_mesh
 
-    mesh = compat.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     t = {"w": jnp.arange(8, dtype=jnp.float32)}
     save(str(tmp_path / "ck"), t, step=1)
     shardings = {"w": NamedSharding(mesh, P("data"))}

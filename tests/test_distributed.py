@@ -12,7 +12,6 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ARCH_IDS, load_arch
 from repro.distributed import sharding as shd
 from repro.launch.mesh import make_production_mesh
@@ -26,8 +25,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 @pytest.fixture(scope="module")
 def meshes():
     # AbstractMesh: spec resolution without needing 512 real devices
-    return [compat.abstract_mesh((16, 16), ("data", "model")),
-            compat.abstract_mesh((2, 16, 16), ("pod", "data", "model"))]
+    return [jax.sharding.AbstractMesh((16, 16), ("data", "model")),
+            jax.sharding.AbstractMesh((2, 16, 16), ("pod", "data", "model"))]
 
 
 @pytest.mark.parametrize("arch", ARCH_IDS)
@@ -70,8 +69,8 @@ SUBPROCESS_PROG = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
-from repro import compat
-mesh = compat.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4, 2), ("data", "model"))
 from repro.configs.base import load_arch
 from repro.models import zoo
 from repro.optim import make_optimizer
@@ -81,7 +80,7 @@ from repro.data.pipeline import SyntheticLMData
 cfg = load_arch("qwen2_0_5b").reduced(n_layers=2, d_model=64, n_heads=4,
                                       n_kv_heads=2, d_ff=128, vocab=256,
                                       head_dim=16)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     params = zoo.init(jax.random.PRNGKey(0), cfg)
     opt = make_optimizer("adamw")
     opt_state = opt.init(params)
@@ -118,7 +117,7 @@ def test_pjit_train_step_executes_on_8_devices():
 
 
 def test_batch_and_cache_specs():
-    mesh = compat.abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    mesh = jax.sharding.AbstractMesh((2, 16, 16), ("pod", "data", "model"))
     cfg = load_arch("qwen2_5_14b")
     batch = {"tokens": jax.ShapeDtypeStruct((256, 128), jnp.int32),
              "odd": jax.ShapeDtypeStruct((3, 4), jnp.float32)}
@@ -140,7 +139,7 @@ def test_cache_specs_paged_layout():
     tree (no attention leaves) resolves instead of crashing."""
     from repro.models import paging
 
-    mesh = compat.abstract_mesh((4,), ("data",))
+    mesh = jax.sharding.AbstractMesh((4,), ("data",))
     cfg = load_arch("qwen2_0_5b").reduced(n_layers=2, d_model=64, n_heads=4,
                                           n_kv_heads=2, d_ff=128, vocab=128,
                                           head_dim=16)

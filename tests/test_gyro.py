@@ -85,3 +85,20 @@ def test_unstructured_upper_bounds_hinm(rng):
     gy = gyro_permute(sal, CFG, ocp_iters=8, icp_iters=8, rng=rng)
     unst = baselines.unstructured_retained(sal, CFG.total_sparsity)
     assert gy.retained_fraction <= unst + 1e-6
+
+
+@pytest.mark.parametrize("cost_mode", ["hinm", "vector"])
+def test_tile_cost_equals_masked_sum(rng, cost_mode):
+    """The search's scatter-free tile cost is the retained saliency the
+    pattern's own mask defines: sum(tile * mask(tile))."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import gyro, sparsity
+
+    tiles = jnp.asarray(structured_sal(rng, m=5 * CFG.v, n=64)
+                        .reshape(5, CFG.v, 64))
+    mask = sparsity.hinm_mask if cost_mode == "hinm" else sparsity.vector_mask
+    want = jax.vmap(lambda t: jnp.sum(t * mask(t, CFG)))(tiles)
+    got = gyro._tile_retained(tiles, CFG, cost_mode)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6)

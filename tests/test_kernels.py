@@ -89,30 +89,47 @@ def test_nm_select_ties_deterministic():
 
 
 def test_pick_bblk_respects_budget():
-    b = pick_bblk(n_in=32768, k=16384, b=1024)
-    # full working set with real itemsizes: xT + gather (activation dtype),
-    # weights (vals + int8 slots + vec_idx), decompress one-hot + dense
-    # tile, f32 accumulator
-    k, v, nn, mm, it = 16384, 32, 2, 4, 2
+    # a decode-sized batch is one block over the whole lane axis
+    assert pick_bblk(896, 448, 8) == 8
+    assert pick_bblk(128, 64, 4) == 4
+    # larger batches take 128-lane multiples whose working set (f32
+    # activation block double-buffered, f32 slot planes, vals + int8 slots
+    # double-buffered plus one masked copy, f32 accumulator + output) fits
+    n_in, k, v, nn, mm, it = 4864, 2432, 32, 2, 4, 2
+    b = pick_bblk(n_in, k, 1024)
+    assert b % 128 == 0
     kn = k // mm * nn
-    ws = (32768 * b * it + k * b * it + v * b * 4
-          + v * kn * (it + 1) + k * 4 + v * kn * mm * it + v * k * it)
+    ws = (2 * n_in * b * 4 + mm * kn * b * 4 + 3 * v * kn * (it + 1)
+          + v * b * (4 + 2 * it))
     assert ws <= 8 * 1024 * 1024
-    assert pick_bblk(128, 64, 4) >= 4
 
 
 def test_pick_bblk_pinned_representative_shapes():
     """Pin the chosen batch block for representative (n_in, k, B, itemsize)
     shapes so VMEM-formula regressions are caught, not silently absorbed.
-    The f32 5120x2560 case is the one the old 4-byte-gather formula got
-    wrong: it picked 256, which overflows the budget once the decompress
-    one-hot transient is counted."""
-    assert pick_bblk(32768, 16384, 1024, 2) == 32
-    assert pick_bblk(13824, 5120, 2048, 2) == 128
-    assert pick_bblk(5120, 2560, 1024, 2) == 256
+    The 5120-wide case does not fit 128 lanes under the budget and takes
+    the 128-lane floor (the lane tiling's minimum)."""
+    assert pick_bblk(896, 448, 512, 2) == 256
+    assert pick_bblk(4864, 2432, 1024, 2) == 128
     assert pick_bblk(5120, 2560, 1024, 4) == 128
     assert pick_bblk(1024, 512, 256, 4) == 256
-    assert pick_bblk(128, 64, 4, 2) == 8
+    assert pick_bblk(896, 448, 129, 2) == 256
+    assert pick_bblk(128, 64, 4, 2) == 4
+
+
+@pytest.mark.parametrize("kernel", ["hinm_matmul", "nm_apply"])
+def test_forced_pallas_without_tpu_raises(rng, monkeypatch, kernel):
+    """"pallas" means the compiled kernel: where JAX's default backend is
+    not a TPU it raises a named error instead of running the interpreter
+    behind the caller's back (only "interpret" asks for that; the paged-
+    attention case is in test_paged_attn.test_kernel_backend_dispatch)."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: False)
+    with pytest.raises(ops.TPUUnavailableError, match="interpret"):
+        if kernel == "hinm_matmul":
+            _, p = make_packed(rng, 16, 16, v=8)
+            ops.hinm_matmul(jnp.ones((4, 16)), p, backend="pallas")
+        else:
+            ops.nm_apply(jnp.ones((8, 16)), backend="pallas")
 
 
 def test_decompress_tiles_matches_unpack(rng):

@@ -139,6 +139,13 @@ def test_kernel_backend_dispatch():
                                    backend="auto") is None
     with pytest.raises(ValueError, match="paged-attention backend"):
         ops.paged_attention(q, kp, vp, kpos, bt, q_pos, backend="nope")
+    # the compiled kernel, forced off-TPU, is an error — never the
+    # interpreter in disguise ("interpret" asks for that by name)
+    if jax.devices()[0].platform != "tpu":
+        for forced in ("pallas", "on"):
+            with pytest.raises(ops.TPUUnavailableError):
+                ops.paged_attention(q, kp, vp, kpos, bt, q_pos,
+                                    backend=forced)
 
 
 # ---------------------------------------------------------------------------
@@ -159,13 +166,20 @@ def test_pick_tile():
 
 
 def test_pick_pp_within_budget():
-    for n_bt, page, hd, gs in [(4, 16, 32, 8), (32, 64, 128, 16),
-                               (128, 256, 128, 8)]:
-        pp = pick_pp(n_bt, page, hd, gs, 2)
+    from repro.kernels.paged_attn import _vmem_bytes
+
+    for n_bt, page, hd, gs, kvh in [(4, 16, 32, 8, 2), (32, 64, 128, 16, 1),
+                                    (128, 256, 128, 8, 2), (9, 64, 64, 7, 2)]:
+        pp = pick_pp(n_bt, page, hd, gs, 2, kvh)
         assert 1 <= pp <= min(8, n_bt) and n_bt % pp == 0
-        per_page = page * hd * (2 + 4) * 2 + page * 4 + gs * page * 4 * 3
-        fixed = gs * hd * 4 * 3 + gs * 128 * 4 * 2 + gs * 4
+        per_page = (2 * 2 * page * _vmem_bytes(kvh, hd, 2)
+                    + 2 * _vmem_bytes(1, page, 4) + 2 * _vmem_bytes(page, hd, 4)
+                    + 3 * _vmem_bytes(gs, page, 4))
+        fixed = kvh * (3 * _vmem_bytes(gs, hd, 4) + 2 * _vmem_bytes(gs, 128, 4))
         assert pp == 1 or fixed + per_page * pp <= ops.VMEM_BUDGET_BYTES
+    # bf16 (2, 64) rows pad to a (16, 128) tile: 16x the logical bytes
+    assert _vmem_bytes(2, 64, 2) == 16 * 128 * 2
+    assert _vmem_bytes(8, 128, 4) == 8 * 128 * 4
 
 
 # ---------------------------------------------------------------------------

@@ -10,8 +10,8 @@ PROG = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
-from repro import compat
-mesh = compat.make_mesh((4,), ("pod",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4,), ("pod",))
 from repro.distributed.pipeline import pipeline_apply
 
 n_stages, n_micro, mb, d = 4, 8, 2, 16
@@ -24,7 +24,7 @@ def stage_fn(p, xm):
     w, b = p
     return jnp.tanh(xm @ w + b)
 
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     y = jax.jit(lambda p, xx: pipeline_apply(stage_fn, p, xx, mesh))((ws, bs), x)
 
 # sequential reference
