@@ -24,12 +24,13 @@ and then checks on the chip that
     masked-dense model (`zoo.unpack_params`, matmul precision "highest").
 
 `--chips 4` runs only the sharded path and what it is compared with: the
-same requests through `Scheduler(mesh=<4-device data mesh>)` with a
-page-sharded pool, and on one of those chips alone (the weights packed by
-`zoo.pack_params`, without the host-side gyro search); greedy tokens must
-match (or the first divergence, named by `flightrec.diff_records`, must be
-a near-tie within the logits tolerance), and the pool's pages must sit on
-all four devices.
+same 8 greedy requests through `Scheduler(mesh=<4-device data mesh>)` with
+a page-sharded pool, and on one of those chips alone through the same
+gather attention path (the weights packed by `zoo.pack_params`, without
+the host-side gyro search); greedy tokens must match (or each request's
+first differing token, the first of them named by
+`flightrec.diff_records`, must be a near-tie within the logits
+tolerance), and the pool's pages must sit on all four devices.
 
 Any failed check, exception or missing accelerator exits non-zero without
 the result line. The last line of standard output is, on success, exactly
@@ -53,6 +54,9 @@ NEW_TOKENS = 32
 # rids 0-2 share a length so one batched forward checks their logits.
 PROMPT_LENS = (384, 384, 384, 128, 512, 160, 448, 256)
 LOGIT_RIDS = (0, 1, 2)
+# --chips 4: greedy requests whose prompts share one length bucket (256),
+# so each side compiles one prefill per group width and no sampler
+FOUR_CHIP_LENS = (256, 240, 224, 208, 200, 232, 248, 216)
 MAX_SEQ = 576                     # 512 + 32 rounded up to whole pages
 OCP_ITERS = ICP_ITERS = 1
 
@@ -64,11 +68,14 @@ OCP_ITERS = ICP_ITERS = 1
 # moves outputs by their own size.
 SPMM_REL_TOL = 1e-3
 # paged_decode_attn vs the gather reference (f32, precision "highest"):
-# q is f32 and the bf16 pool upcasts exactly, but the kernel's f32 dots
-# may take single bf16 MXU passes (2**-9 relative per product); outputs
-# are O(1) averages of unit-normal V rows. A wrong page, row or mask
-# moves an output by O(1).
-ATTN_ABS_TOL = 2e-2
+# q is f32, the bf16 pool upcasts exactly, and the kernel's dots contract
+# at f32 precision, so the two differ by f32 rounding and exp() alone
+# (~1e-6 on O(1) outputs, averages of unit-normal V rows). The control is
+# the same reference at the chip's default precision, one bf16 MXU pass
+# per dot (2**-9 relative per product): ~1e-3. The bound sits between the
+# two, so a kernel that dropped to bf16 passes fails it; a wrong page, row
+# or mask moves an output by O(1).
+ATTN_ABS_TOL = 1e-4
 # served logits vs the f32 masked-dense forward: weights are the same
 # bf16 numbers in both, but the served path stores every activation and
 # the KV cache in bf16 (unit roundoff 2**-9) through 24 layers. The bound
@@ -116,15 +123,15 @@ def build_model(cfg, seed: int):
     return packed
 
 
-def workload(cfg, seed: int):
+def workload(cfg, seed: int, lens=PROMPT_LENS, sample: bool = True):
     import numpy as np
 
     from repro.serve import Request, SamplingParams
 
     rng = np.random.default_rng(seed)
     reqs = []
-    for i, n in enumerate(PROMPT_LENS):
-        sampled = i % 2 == 1
+    for i, n in enumerate(lens):
+        sampled = sample and i % 2 == 1
         reqs.append(Request(
             rid=i,
             prompt=rng.integers(0, cfg.vocab, (n,)).astype(np.int32),
@@ -253,14 +260,21 @@ def check_paged_attn(cfg, sched, seed: int) -> None:
         q = jnp.asarray(rng.normal(size=(b, s, cfg.n_heads, hd)), jnp.float32)
         args = (q, kp, vp, jnp.asarray(kpos), jnp.asarray(bt), q_pos)
         got = ops.paged_attention(*args, backend="pallas")
-        with jax.default_matmul_precision("highest"):
-            want = layers._attn_chunked(
+
+        def gather_ref():
+            return layers._attn_chunked(
                 q, paging.gather_view(kp, args[4]).astype(jnp.float32),
                 paging.gather_view(vp, args[4]).astype(jnp.float32), q_pos,
                 paging.gather_view(args[3], args[4]), True, 0, 1024)
+
+        with jax.default_matmul_precision("highest"):
+            want = gather_ref()
+        control = gather_ref()                 # default precision: bf16 passes
         err = float(jnp.abs(got - want).max())
+        err_c = float(jnp.abs(control - want).max())
         log(f"paged_decode_attn B={b} s={s} pool {n_pages}x{page}x{kvh}x{hd}"
-            f": max|err| {err:.3e}")
+            f": max|err| {err:.3e} (bound {ATTN_ABS_TOL:.0e}); bf16-pass "
+            f"control {err_c:.3e}")
         check(err <= ATTN_ABS_TOL, f"paged_decode_attn s={s} off the gather")
 
 
@@ -278,21 +292,25 @@ def check_nm_select(seed: int) -> None:
     check(same, "nm_select differs from the oracle")
 
 
-def served_logits(cfg, params, prompts):
-    """First-step logits of the served path: the packed params through the
-    scheduler's own prefill + vocab projection (bf16, compiled kernels)."""
+def served_logits(sched, prompts):
+    """First-step logits of the served path: the scheduler's packed params
+    through prefill into its own stripe template (prefill always runs on
+    stripes; the pool insert only moves rows) and the vocab projection
+    (bf16, compiled kernels)."""
     import jax
     import jax.numpy as jnp
 
     from repro.models import zoo
 
+    cfg = sched.cfg
+
     @jax.jit
-    def fn(params, tokens):
-        cache = zoo.make_cache(cfg, tokens.shape[0], MAX_SEQ)
+    def fn(params, tokens, cache):
         last, _ = zoo.prefill(params, cfg, tokens, cache)
         return zoo.logits_fn(params, cfg, last)[:, :cfg.vocab]
 
-    return fn(params, jnp.asarray(prompts)).astype(jnp.float32)
+    return fn(sched.params, jnp.asarray(prompts),
+              sched.kv.template(len(prompts))).astype(jnp.float32)
 
 
 def reference_logits(cfg, params, prompts):
@@ -318,22 +336,30 @@ def reference_logits(cfg, params, prompts):
     return fn(dense, jnp.asarray(prompts))
 
 
-def check_logits(cfg, params, reqs) -> None:
+def check_logits(sched, reqs) -> None:
+    """The served prefill's logits against the f32 reference, and the
+    scheduler's own first token, for greedy requests, against the
+    reference's argmax: equal, or a near-tie within the same bound."""
     import jax.numpy as jnp
     import numpy as np
 
     prompts = np.stack([r.prompt for r in reqs])
-    got = served_logits(cfg, params, prompts)
-    want = reference_logits(cfg, params, prompts)
+    got = served_logits(sched, prompts)
+    want = reference_logits(sched.cfg, sched.params, prompts)
     for i, r in enumerate(reqs):
         err = float(jnp.abs(got[i] - want[i]).max())
         spread = float(want[i].std())
+        top = int(want[i].argmax())
+        short = float(want[i][top] - want[i][r.tokens[0]])
         log(f"logits rid={r.rid} prompt {len(r.prompt)}: max|err| {err:.4f}, "
             f"ref std {spread:.4f} (ratio {err / spread:.4f}); argmax "
-            f"served {int(got[i].argmax())} ref {int(want[i].argmax())}, "
-            f"first token served {r.tokens[0]}")
+            f"served {int(got[i].argmax())} ref {top}; scheduler's first "
+            f"token {r.tokens[0]}, {short:.4f} under the ref max")
         check(err <= LOGIT_REL_TOL * spread,
               f"rid {r.rid}: served logits off the f32 reference")
+        check(r.params.temperature > 0 or short <= LOGIT_REL_TOL * spread,
+              f"rid {r.rid}: the scheduler's first token {r.tokens[0]} is "
+              f"not the reference argmax {top} nor within the bound of it")
 
 
 # ---------------------------------------------------------------------------
@@ -385,17 +411,19 @@ def one_chip(cfg, seed: int) -> None:
     check_spmm(cfg, packed, seed)
     check_paged_attn(cfg, sched, seed)
     check_nm_select(seed)
-    check_logits(cfg, sched.params, sorted(
+    check_logits(sched, sorted(
         (r for r in first if r.rid in LOGIT_RIDS), key=lambda r: r.rid))
     jax.block_until_ready(sched.kv.cache)
 
 
 def four_chips(cfg, seed: int) -> None:
     import jax
+    import jax.numpy as jnp
     import numpy as np
 
     from repro.launch.mesh import make_mesh
     from repro.models import paging, zoo
+    from repro.perf_knobs import knobs
     from repro.serve import Scheduler
     from repro.serve.flightrec import diff_records
 
@@ -405,53 +433,90 @@ def four_chips(cfg, seed: int) -> None:
     packed = zoo.pack_params(cfg, zoo.init(jax.random.PRNGKey(seed), cfg))
     clock = CompileClock()
     n_bt = MAX_SEQ // PAGE
-    n_pages = paging.shard_geometry(len(PROMPT_LENS) * n_bt, 4)["n_alloc"]
-    kw = dict(max_slots=len(PROMPT_LENS), max_seq=MAX_SEQ, page=PAGE,
+    n_pages = paging.shard_geometry(len(FOUR_CHIP_LENS) * n_bt, 4)["n_alloc"]
+    kw = dict(max_slots=len(FOUR_CHIP_LENS), max_seq=MAX_SEQ, page=PAGE,
               n_pages=n_pages, policy="continuous", flightrec=True)
+
+    def requests():
+        return workload(cfg, seed, FOUR_CHIP_LENS, sample=False)
+
+    def attn_route(sched):
+        return [(ev.data["backend"], ev.data["defer"])
+                for ev in sched.flight.events if ev.kind == "dispatch"]
+
     mesh = make_mesh((4,), ("data",))
     sharded = Scheduler(cfg, packed, mesh=mesh, **kw)
     check(sharded.kv.page_sharded, "the pool is not page-sharded")
     shards = sharded.kv.cache["k"].addressable_shards
     placed = sorted((s.index[1].start, s.device.id) for s in shards)
     log(f"sharded pool: {sharded.kv.n_pages} pages; (first page, device) "
-        f"per shard {placed}")
+        f"per shard {placed}; attention {attn_route(sharded)}")
     check(len({d for _, d in placed}) == 4
           and len({p for p, _ in placed}) == 4
           and all(s.data.shape[1] == sharded.kv.n_pages // 4 for s in shards),
           "pool pages are not spread over all four devices")
-    done_s = serve(sharded, workload(cfg, seed), clock, "sharded (4 chips)")
+    done_s = serve(sharded, requests(), clock, "sharded (4 chips)")
 
-    with jax.default_device(jax.devices()[0]):
+    # like for like: the page-sharded pool attends through the SPMD gather
+    # path, so the one-chip run takes the same path; only sharding differs
+    with jax.default_device(jax.devices()[0]), knobs(paged_attn="off"):
         single = Scheduler(cfg, packed, **kw)
-        done_1 = serve(single, workload(cfg, seed), clock, "one chip")
+        log(f"one chip: attention {attn_route(single)}")
+        check(attn_route(single) == [("off", None)]
+              and attn_route(sharded) == [("off", "page-sharded-pool")],
+              "the two runs do not attend through the same gather path")
+        done_1 = serve(single, requests(), clock, "one chip")
     by_rid = {r.rid: r for r in done_1}
-    greedy = {r.rid: r for r in done_s if r.params.temperature <= 0.0}
-    same = [rid for rid, r in greedy.items() if r.tokens == by_rid[rid].tokens]
+    by_rid_s = {r.rid: r for r in done_s}
+    diverged = {rid: next(i for i, (a, b) in enumerate(
+        zip(r.tokens, by_rid[rid].tokens)) if a != b)
+        for rid, r in by_rid_s.items() if r.tokens != by_rid[rid].tokens}
     log(f"greedy requests token-identical sharded vs one chip: "
-        f"{len(same)}/{len(greedy)}")
-    if len(same) == len(greedy):
+        f"{len(done_s) - len(diverged)}/{len(done_s)}")
+    if not diverged:
         return
 
     def token_events(sched):   # page ids differ by design on a sharded pool
         return [ev for ev in sched.flight.events
-                if ev.kind in ("commit", "emit", "finish")
-                and ev.data["rid"] in greedy]
+                if ev.kind in ("commit", "emit", "finish")]
 
     first = diff_records(token_events(sharded), token_events(single)).first
-    log(f"first divergence: {first.describe()}")
-    r, ref = greedy[first.stream[1]], by_rid[first.stream[1]]
-    i = next(j for j, (a, b) in enumerate(zip(r.tokens, ref.tokens)) if a != b)
-    prefix = np.concatenate([r.prompt, ref.tokens[:i]]).astype(np.int32)
+    log(f"first divergence: "
+        f"{first.describe() if first else 'none among the token events'}")
+    # each diverging request's first differing token must be a near-tie:
+    # the two tokens' logits on one chip, after the common prefix, closer
+    # than the bound the served path is held to against the f32 reference.
+    # One bf16 forward of the packed model (causal, so right-padding the
+    # prefixes to MAX_SEQ changes nothing at their last positions).
+    rids = sorted(diverged)
+    prefix = np.zeros((len(rids), MAX_SEQ), np.int32)
+    last = []
+    for row, rid in enumerate(rids):
+        p = np.concatenate([by_rid[rid].prompt,
+                            by_rid[rid].tokens[:diverged[rid]]])
+        prefix[row, :len(p)] = p
+        last.append(len(p) - 1)
+
+    @jax.jit
+    def logits_at(params, tokens, last):
+        x = zoo.forward(params, cfg, tokens, remat=False)
+        x = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
+        return zoo.logits_fn(params, cfg, x)[:, :cfg.vocab].astype(jnp.float32)
+
     with jax.default_device(jax.devices()[0]):
-        logits = served_logits(cfg, single.params, prefix[None])[0]
-    # a near-tie: the two tokens' logits are closer than the noise bound
-    # the served path is held to against the f32 reference
-    gap = float(abs(logits[r.tokens[i]] - logits[ref.tokens[i]]))
-    spread = float(logits.std())
-    log(f"rid {r.rid} token {i}: sharded {r.tokens[i]} vs one chip "
-        f"{ref.tokens[i]}; one-chip logit gap {gap:.4f}, std {spread:.4f}")
-    check(gap <= LOGIT_REL_TOL * spread,
-          f"rid {r.rid}: divergence at token {i} is not a near-tie")
+        logits = logits_at(single.params, jnp.asarray(prefix),
+                           jnp.asarray(last, jnp.int32))
+    for row, rid in enumerate(rids):
+        i = diverged[rid]
+        a, b = by_rid_s[rid].tokens[i], by_rid[rid].tokens[i]
+        gap = float(abs(logits[row, a] - logits[row, b]))
+        spread = float(logits[row].std())
+        log(f"rid {rid} token {i}: sharded {a} vs one chip {b}; one-chip "
+            f"forward argmax {int(logits[row].argmax())}, logit gap "
+            f"{gap:.4f}, std {spread:.4f} (ratio {gap / spread:.4f}, bound "
+            f"{LOGIT_REL_TOL})")
+        check(gap <= LOGIT_REL_TOL * spread,
+              f"rid {rid}: divergence at token {i} is not a near-tie")
 
 
 def main() -> int:

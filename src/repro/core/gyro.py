@@ -37,6 +37,7 @@ CostMode = Literal["hinm", "vector"]
 # ---------------------------------------------------------------------------
 
 
+@functools.partial(jax.jit, static_argnames=("cfg", "cost_mode"))
 def _retained(tiles: jax.Array, cfg: HiNMConfig, cost_mode: str) -> jax.Array:
     """Retained saliency of each (V, n_in) tile under the target pattern.
 
@@ -56,9 +57,6 @@ def _retained(tiles: jax.Array, cfg: HiNMConfig, cost_mode: str) -> jax.Array:
         return jnp.sum(jax.lax.top_k(groups, cfg.n)[0])
 
     return jax.vmap(one)(tiles)
-
-
-_tile_retained = jax.jit(_retained, static_argnames=("cfg", "cost_mode"))
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "cost_mode"))
@@ -132,7 +130,7 @@ def ocp(
 
     def total_retained(perm_np: np.ndarray) -> float:
         tiles = jnp.asarray(sal[perm_np].reshape(p, v, n_in))
-        return float(_tile_retained(tiles, cfg, cost_mode).sum())
+        return float(_retained(tiles, cfg, cost_mode).sum())
 
     best = total_retained(perm)
     history = [best]
@@ -328,18 +326,6 @@ def icp(
         stall[live[~better[live]]] += 1
         history.append(best.copy())
     return orders, np.stack(history)
-
-
-def icp_tile(
-    tile: np.ndarray,
-    cfg: HiNMConfig,
-    iters: int = 16,
-    patience: int = 4,
-) -> tuple[np.ndarray, list[float]]:
-    """ICP on one (V, K) tile. Returns (order, history)."""
-    orders, history = icp(np.asarray(tile)[None], cfg, iters=iters,
-                          patience=patience)
-    return orders[0], [float(h) for h in history[:, 0]]
 
 
 # ---------------------------------------------------------------------------

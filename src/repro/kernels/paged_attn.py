@@ -55,6 +55,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 LANES = 128
+# both dots contract f32 operands at full f32 precision (several MXU
+# passes), as the gather reference does under "highest"; the default
+# would round them to one bf16 pass
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _kernel(bt_ref, q_ref, qpos_ref, kpos_ref, *refs, pp: int, window: int):
@@ -83,6 +87,7 @@ def _kernel(bt_ref, q_ref, qpos_ref, kpos_ref, *refs, pp: int, window: int):
             vj = v_refs[j][0, :, h, :].astype(jnp.float32)
             s = jax.lax.dot_general(
                 q, kj, dimension_numbers=(((1,), (1,)), ((), ())),
+                precision=HIGHEST,
                 preferred_element_type=jnp.float32)           # (Gs, page)
             s = jnp.where(msk, s, NEG_INF)
             m_prev = m_ref[h][:, :1]
@@ -92,7 +97,7 @@ def _kernel(bt_ref, q_ref, qpos_ref, kpos_ref, *refs, pp: int, window: int):
             l_new = l_ref[h][:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
             acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
                 p, vj, dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+                precision=HIGHEST, preferred_element_type=jnp.float32)
             m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
             l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
 

@@ -3,7 +3,7 @@ import numpy as np
 import pytest
 
 from repro.core import baselines
-from repro.core.gyro import gyro_permute, icp_tile, ocp
+from repro.core.gyro import gyro_permute, icp, ocp
 from repro.core.types import HiNMConfig
 
 CFG = HiNMConfig(v=8, n=2, m=4, vector_sparsity=0.5)
@@ -25,9 +25,9 @@ def test_ocp_returns_bijection(rng):
 
 def test_icp_tile_bijection_and_improvement(rng):
     tile = structured_sal(rng, 8, 16)
-    order, hist = icp_tile(tile, CFG, iters=8)
-    assert sorted(order.tolist()) == list(range(16))
-    assert hist[-1] >= hist[0] - 1e-6
+    orders, hist = icp(tile[None], CFG, iters=8)    # one tile
+    assert sorted(orders[0].tolist()) == list(range(16))
+    assert hist[-1, 0] >= hist[0, 0] - 1e-6
 
 
 def test_gyro_beats_noperm(rng):
@@ -100,5 +100,5 @@ def test_tile_cost_equals_masked_sum(rng, cost_mode):
                         .reshape(5, CFG.v, 64))
     mask = sparsity.hinm_mask if cost_mode == "hinm" else sparsity.vector_mask
     want = jax.vmap(lambda t: jnp.sum(t * mask(t, CFG)))(tiles)
-    got = gyro._tile_retained(tiles, CFG, cost_mode)
+    got = gyro._retained(tiles, CFG, cost_mode)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6)

@@ -7,6 +7,7 @@ import pytest
 from repro.core import packing
 from repro.core.types import HiNMConfig
 from repro.kernels import ops, ref
+from repro.kernels import hinm_spmm as spmm_mod
 from repro.kernels.hinm_spmm import hinm_spmm, pick_bblk
 from repro.kernels.nm_select import nm_select
 
@@ -24,6 +25,7 @@ SHAPES = [
     (32, 64, 33, 16),   # batch not divisible by block
     (128, 96, 7, 32),
     (64, 128, 129, 8),
+    (32, 2304, 4, 32),  # Kn=576: two KN_CHUNK row chunks, the second padded
 ]
 
 
@@ -92,29 +94,47 @@ def test_pick_bblk_respects_budget():
     # a decode-sized batch is one block over the whole lane axis
     assert pick_bblk(896, 448, 8) == 8
     assert pick_bblk(128, 64, 4) == 4
-    # larger batches take 128-lane multiples whose working set (f32
-    # activation block double-buffered, f32 slot planes, vals + int8 slots
-    # double-buffered plus one masked copy, f32 accumulator + output) fits
-    n_in, k, v, nn, mm, it = 4864, 2432, 32, 2, 4, 2
-    b = pick_bblk(n_in, k, 1024)
-    assert b % 128 == 0
-    kn = k // mm * nn
-    ws = (2 * n_in * b * 4 + mm * kn * b * 4 + 3 * v * kn * (it + 1)
-          + v * b * (4 + 2 * it))
-    assert ws <= 8 * 1024 * 1024
+    # larger batches take 128-lane multiples; the full working set (f32
+    # activation block double-buffered, f32 slot planes of one row chunk,
+    # vals + int8 slots double-buffered plus one masked copy, f32
+    # accumulator + output) stays under the budget the block is sized
+    # against where 256 lanes fit, and under the VMEM cap at any width
+    v, nn, mm, it = 32, 2, 4, 2
+    for n_in, k, cap in ((4864, 2432, spmm_mod.VMEM_BUDGET_BYTES),
+                         (32768, 16384, spmm_mod.VMEM_CAP_BYTES)):
+        b = pick_bblk(n_in, k, 1024)
+        assert b % 128 == 0
+        kc = min(k // mm * nn, spmm_mod.KN_CHUNK)
+        ws = (2 * n_in * b * 4 + mm * kc * b * 4 + 3 * v * kc * (it + 1)
+              + v * b * (4 + 2 * it))
+        assert ws == spmm_mod.vmem_bytes(n_in, k, b)
+        assert ws <= cap
+    assert spmm_mod.VMEM_CAP_BYTES < 128 * 1024 * 1024  # v5e VMEM per core
 
 
 def test_pick_bblk_pinned_representative_shapes():
     """Pin the chosen batch block for representative (n_in, k, B, itemsize)
     shapes so VMEM-formula regressions are caught, not silently absorbed.
-    The 5120-wide case does not fit 128 lanes under the budget and takes
-    the 128-lane floor (the lane tiling's minimum)."""
+    The wide layers (grok-1's 32768 down projection, qwen2.5-14b's 13824)
+    take the 128-lane floor, whose working set stays under the VMEM cap
+    because the kernel cuts Kn into KN_CHUNK-row chunks."""
+    assert pick_bblk(32768, 16384, 1024, 2) == 128
+    assert pick_bblk(13824, 5120, 2048, 2) == 128
+    assert pick_bblk(5120, 2560, 1024, 2) == 128
     assert pick_bblk(896, 448, 512, 2) == 256
     assert pick_bblk(4864, 2432, 1024, 2) == 128
     assert pick_bblk(5120, 2560, 1024, 4) == 128
     assert pick_bblk(1024, 512, 256, 4) == 256
     assert pick_bblk(896, 448, 129, 2) == 256
     assert pick_bblk(128, 64, 4, 2) == 4
+
+
+@pytest.mark.parametrize("n_in,b", [(200_000, 1024), (400_000, 64)])
+def test_pick_bblk_too_wide_raises(n_in, b):
+    """A layer whose smallest batch block cannot sit in VMEM is refused by
+    name rather than handed to the compiler."""
+    with pytest.raises(spmm_mod.VMEMBudgetError, match="VMEM"):
+        pick_bblk(n_in, n_in // 2, b)
 
 
 @pytest.mark.parametrize("kernel", ["hinm_matmul", "nm_apply"])

@@ -32,6 +32,9 @@ ATTN, KV = CFG.n_heads * CFG.head_dim, CFG.n_kv_heads * CFG.head_dim
 # (n_in, n_out) of every projection the packed model serves
 PROJECTIONS = {"q": (D, ATTN), "kv": (D, KV), "o": (ATTN, D),
                "gate_up": (D, FF), "down": (FF, D)}
+# the widest down projections of the repo's larger configs (qwen2.5-14b,
+# grok-1): the resident activation block is largest there
+WIDE = {"qwen2_5_14b_down": (13824, 5120), "grok_1_down": (32768, 6144)}
 PAGE, N_BT, N_PAGES, SLOTS = 64, 9, 80, 8   # the smoke's served pool
 
 
@@ -82,6 +85,16 @@ def test_hinm_spmm_compiles(one_chip, proj, batch):
     n_in, n_out = PROJECTIONS[proj]
     vals, nm_idx, vec_idx = _packed_shapes(n_in, n_out, one_chip)
     x_t = jax.ShapeDtypeStruct((n_in, batch), CFG.dtype, sharding=one_chip)
+    _compile(lambda x, a, b, c: hinm_spmm(x, a, b, c, nn=CFG.hinm.n,
+                                          mm=CFG.hinm.m),
+             x_t, vals, nm_idx, vec_idx)
+
+
+@pytest.mark.parametrize("proj", list(WIDE))
+def test_hinm_spmm_compiles_wide(one_chip, proj):
+    n_in, n_out = WIDE[proj]
+    vals, nm_idx, vec_idx = _packed_shapes(n_in, n_out, one_chip)
+    x_t = jax.ShapeDtypeStruct((n_in, 512), CFG.dtype, sharding=one_chip)
     _compile(lambda x, a, b, c: hinm_spmm(x, a, b, c, nn=CFG.hinm.n,
                                           mm=CFG.hinm.m),
              x_t, vals, nm_idx, vec_idx)
